@@ -28,6 +28,7 @@ VMEM pass as the gather (opt-in via ``ServingEngine(fused_gather_rope=True)``)
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -40,13 +41,16 @@ from repro.models.blocks import block_preproj, preproj_layout
 from repro.models.transformer import layer_plan
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=['table'], meta_fields=['layout', 'name'])
 @dataclasses.dataclass
 class PrecomputedTable:
     """Expanded embedding table + row layout.
 
     ``table``: (vocab, row_width). ``layout``: ((name, width), ...) in storage
     order. ``gather`` returns the named pieces for a batch of token ids —
-    the paper's "one memory read per token".
+    the paper's "one memory read per token". A pytree (``table`` is its one
+    leaf), so jitted programs take it as an argument.
     """
     table: jax.Array
     layout: Tuple[Tuple[str, int], ...]

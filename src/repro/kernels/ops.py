@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.embed_gather import embed_gather
-from repro.kernels.gather_rope import gather_rope
+from repro.kernels.gather_rope import gather_rope, tile_rows
 from repro.kernels.rmsnorm_qkv import rmsnorm_matmul
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.decode_attention import decode_attention
@@ -29,6 +29,22 @@ def _interpret() -> bool:
     resolves here, so no caller silently runs interpreted on real hardware.
     """
     return jax.default_backend() != 'tpu'
+
+
+def on_every_device(call, *args):
+    """Run one Pallas call whole on every device of the mesh being traced.
+
+    The compiler cannot partition a Mosaic kernel, so a kernel traced for a
+    mesh (``jax.set_mesh``) runs replicated inside a ``shard_map``: each
+    device computes the full result from its (replicated) operands. Outside
+    a mesh, or inside a ``shard_map`` body, it is the plain call.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.are_all_axes_manual:
+        return call(*args)
+    rep = jax.sharding.PartitionSpec()
+    return jax.shard_map(call, mesh=mesh, in_specs=(rep,) * len(args),
+                         out_specs=rep, check_vma=False)(*args)
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int, value=0) -> jax.Array:
@@ -77,7 +93,7 @@ def gather_rope_rows_segs(table: jax.Array, ids: jax.Array,
     plus the shared ``k_pe`` slice).
     """
     W = table.shape[1]
-    tp = _pad_to(table, 128, axis=1)
+    tp = _pad_to(_pad_to(table, 128, axis=1), tile_rows(table.dtype), axis=0)
     flat_ids = ids.reshape(-1).astype(jnp.int32)
     flat_pos = positions.reshape(-1).astype(jnp.int32)
     rows = gather_rope(tp, flat_ids, flat_pos, segs=tuple(segs),

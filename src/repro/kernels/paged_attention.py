@@ -4,10 +4,10 @@ The serving engine's decode hot loop is memory-bandwidth-bound: every step
 streams the whole KV working set HBM->VMEM once. The reference paged path
 pays that twice — it first *gathers* each slot's pages into a dense-shaped
 ``(B, Sc, ...)`` virtual cache per layer per step, then attends over the
-copy. This kernel removes the copy: the grid runs over
-``(batch, kv_heads, pages)`` and each step DMAs ONE physical page of the
-global pool straight into VMEM through the per-slot page table (the table
-is scalar-prefetched, so page ``j``'s DMA is issued before the body runs).
+copy. This kernel removes the copy: the grid runs over ``(batch, pages)``
+and each step DMAs ONE physical page of the global pool (all its kv heads)
+straight into VMEM through the per-slot page table (the table is
+scalar-prefetched, so page ``j``'s DMA is issued before the body runs).
 
 It also removes the reference path's query-lane serialisation: all T query
 lanes of a prefill chunk are batched into a single dispatch (one
@@ -21,7 +21,8 @@ T=1 case of this kernel).
 
 Variants (static flags):
 - ``quant``: int8 K/V pages with per-(token, head) scales folded into the
-  scores and the value mix, matching ``attention._attend_lanes``' order.
+  scores and the value mix, matching ``attention._attend_lanes``' order
+  (the int8 rows themselves stay exact through a bf16 MXU pass).
 - ``mla_split > 0``: MLA latent attention — query rows are
   ``[q_absorbed | q_pe]``, scores are ``q_abs·ckv^T + q_pe·kpe^T`` and the
   value mix re-reads the ckv pages (MLA caches no separate V).
@@ -38,9 +39,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -2.0 ** 30   # large-negative that survives bf16
 
 
-def page_validity(cpos: jax.Array, pos_t: jax.Array, window: int
+def page_validity(cpos: jax.Array, qpos: jax.Array, window: int
                   ) -> jax.Array:
-    """(ps,) stored positions x (T,) query positions -> (T, ps) validity.
+    """(1, ps) stored positions x (R, 1) query positions -> (R, ps) validity.
 
     A cache entry is attendable iff it was ever written (``pos >= 0``), is
     causal history for the query (``stored <= query``) and, on sliding-window
@@ -48,14 +49,20 @@ def page_validity(cpos: jax.Array, pos_t: jax.Array, window: int
     and null-page reads need no special cases: all of them surface as
     ``pos == -1`` or out-of-window stored positions.
     """
-    v = (cpos[None, :] >= 0) & (cpos[None, :] <= pos_t[:, None])
+    v = (cpos >= 0) & (cpos <= qpos)
     if window:
-        v &= (pos_t[:, None] - cpos[None, :]) < window
+        v &= (qpos - cpos) < window
     return v
 
 
-def _paged_kernel(pos0_ref, table_ref, q_ref, *refs, n_j, window, scale,
-                  quant, mla_split):
+def _nt_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a (M, d) . b (N, d)^T -> (M, N) in fp32, without materialising b^T."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _paged_kernel(pos0_ref, table_ref, roff_ref, q_ref, *refs, n_j, window,
+                  scale, quant, mla_split):
     refs = list(refs)
     k_ref = refs.pop(0)
     if mla_split:
@@ -70,7 +77,7 @@ def _paged_kernel(pos0_ref, table_ref, q_ref, *refs, n_j, window, scale,
     o_ref, m_scr, l_scr, acc_scr = refs
 
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -78,50 +85,42 @@ def _paged_kernel(pos0_ref, table_ref, q_ref, *refs, n_j, window, scale,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0].astype(jnp.float32)                  # (T, G, dq)
-    T, G, dq = q.shape
-    q2 = q.reshape(T * G, dq)
-    cp = cpos_ref[0]                                        # (ps,) int32
-    ps = cp.shape[0]
-    pos_t = pos0_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)[:, 0]
-    valid = page_validity(cp, pos_t, window)                # (T, ps)
-    valid = jnp.broadcast_to(valid[:, None, :], (T, G, ps)) \
-        .reshape(T * G, ps)
+    qpos = pos0_ref[b] + roff_ref[...]                      # (R, 1)
+    valid = page_validity(cpos_ref[0], qpos, window)        # (R, ps)
+    if quant:                                   # (ps, KV) -> (KV, ps) rows
+        ks_rows = ks_ref[0].astype(jnp.float32).T
+        vs_rows = vs_ref[0].astype(jnp.float32).T
 
-    if mla_split:
-        k1 = k_ref[0, :, 0].astype(jnp.float32)             # (ps, r)
-        k2 = k2_ref[0, :, 0].astype(jnp.float32)            # (ps, dr)
-        s = jnp.dot(q2[:, :mla_split], k1.T,
-                    preferred_element_type=jnp.float32) \
-            + jnp.dot(q2[:, mla_split:], k2.T,
-                      preferred_element_type=jnp.float32)
-        v = k1
-    else:
-        k = k_ref[0, :, 0].astype(jnp.float32)              # (ps, dk)
-        s = jnp.dot(q2, k.T, preferred_element_type=jnp.float32)
-        if quant:
-            s = s * ks_ref[0, :, 0].astype(jnp.float32)[None, :]
-        v = v_ref[0, :, 0].astype(jnp.float32)              # (ps, dv)
-    s = s * scale
-    s = jnp.where(valid, s, NEG_INF)
+    for h in range(q_ref.shape[1]):       # every kv head of this page
+        q = q_ref[0, h].astype(jnp.float32)                 # (R, dq)
+        if mla_split:
+            k1 = k_ref[0, :, h].astype(jnp.float32)         # (ps, r)
+            k2 = k2_ref[0, :, h].astype(jnp.float32)        # (ps, dr)
+            s = _nt_dot(q[:, :mla_split], k1) + _nt_dot(q[:, mla_split:], k2)
+            v = k1
+        else:
+            k = k_ref[0, :, h].astype(jnp.float32)          # (ps, dk)
+            v = v_ref[0, :, h].astype(jnp.float32)          # (ps, dv)
+            s = _nt_dot(q, k)
+            if quant:           # per-token scales on the scores
+                s = s * ks_rows[h:h + 1]
+        s = jnp.where(valid, s * scale, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid, p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-    if quant:
-        p = p * vs_ref[0, :, 0].astype(jnp.float32)[None, :]
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+        m_prev = m_scr[h]                                   # (R, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:               # ... and on the value mix
+            p = p * vs_rows[h:h + 1]
+        acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_scr[h] = m_new
 
     @pl.when(j == n_j - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0] = (acc_scr[...] / l[:, None]) \
-            .reshape(T, G, -1).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=('scale', 'window', 'mla_split',
@@ -149,62 +148,71 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
     value mix reads ``k_pages`` (ckv) again. ``k/v_scale_pages``
     (NP, ps, KV) enable the int8 pool. The kernel never materialises a
     gathered cache: page ``table[b, j]`` is read in place on grid step j.
+
+    Mosaic tiles the last two dims of every block by (8, 128) unless they
+    span the whole array, so each grid step takes ALL kv heads of a page
+    (page blocks end in the full ``(KV, d)``), the queries are laid out
+    head-major as ``(B, KV, T*G, dq)`` (one row per (lane, group)), and the
+    stored positions are read as ``(NP, 1, ps)`` rows.
     """
+    from repro.kernels.ops import _interpret, on_every_device
     if interpret is None:
-        from repro.kernels.ops import _interpret
         interpret = _interpret()
     B, T, KV, G, dq = q.shape
     NP, ps = k_pages.shape[:2]
     P = table.shape[1]
+    R = T * G
     quant = k_scale_pages is not None
     dv = mla_split if mla_split else v_pages.shape[-1]
 
-    def page_spec(dk):
-        return pl.BlockSpec((1, ps, 1, dk),
-                            lambda b, h, j, pos0_ref, tab: (tab[b, j], 0, h, 0))
+    def page_spec(pages):
+        return pl.BlockSpec((1, ps) + pages.shape[2:],
+                            lambda b, j, p0, tab: (tab[b, j], 0, 0, 0))
 
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, R, dq)
+    roff = (jnp.arange(R, dtype=jnp.int32) // G)[:, None]   # row -> lane
     in_specs = [
-        pl.BlockSpec((1, T, 1, G, dq),
-                     lambda b, h, j, pos0_ref, tab: (b, 0, h, 0, 0)),
-        page_spec(k_pages.shape[-1]),
+        pl.BlockSpec((R, 1), lambda b, j, p0, tab: (0, 0)),
+        pl.BlockSpec((1, KV, R, dq), lambda b, j, p0, tab: (b, 0, 0, 0)),
+        page_spec(k_pages),
     ]
-    operands = [q, k_pages]
-    if mla_split:
-        in_specs.append(page_spec(k2_pages.shape[-1]))
-        operands.append(k2_pages)
-    else:
-        in_specs.append(page_spec(v_pages.shape[-1]))
-        operands.append(v_pages)
+    operands = [roff, qh, k_pages]
+    v_like = k2_pages if mla_split else v_pages
+    in_specs.append(page_spec(v_like))
+    operands.append(v_like)
     if quant:
-        sc_spec = pl.BlockSpec((1, ps, 1),
-                               lambda b, h, j, pos0_ref, tab: (tab[b, j], 0, h))
+        sc_spec = pl.BlockSpec((1, ps, KV),
+                               lambda b, j, p0, tab: (tab[b, j], 0, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale_pages, v_scale_pages]
-    in_specs.append(pl.BlockSpec((1, ps),
-                                 lambda b, h, j, pos0_ref, tab: (tab[b, j], 0)))
-    operands.append(cpos_pages)
+    in_specs.append(pl.BlockSpec((1, 1, ps),
+                                 lambda b, j, p0, tab: (tab[b, j], 0, 0)))
+    operands.append(cpos_pages.reshape(NP, 1, ps))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # pos0, table
-        grid=(B, KV, P),
+        grid=(B, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T, 1, G, dv),
-                               lambda b, h, j, pos0_ref, tab: (b, 0, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, R, dv),
+                               lambda b, j, p0, tab: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((T * G,), jnp.float32),
-            pltpu.VMEM((T * G,), jnp.float32),
-            pltpu.VMEM((T * G, dv), jnp.float32),
+            pltpu.VMEM((KV, R, 1), jnp.float32),
+            pltpu.VMEM((KV, R, 1), jnp.float32),
+            pltpu.VMEM((KV, R, dv), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, n_j=P, window=window,
                                scale=float(scale), quant=quant,
                                mla_split=mla_split)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, KV, G, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, dv), q.dtype),
         interpret=interpret,
-    )(pos0.astype(jnp.int32), table.astype(jnp.int32), *operands)
+    )
+    out = on_every_device(call, pos0.astype(jnp.int32),
+                          table.astype(jnp.int32), *operands)
+    return out.reshape(B, KV, T, G, dv).transpose(0, 2, 1, 3, 4)
 
 
 def sharded_paged_attention(q: jax.Array, k_pages: jax.Array,
@@ -219,10 +227,9 @@ def sharded_paged_attention(q: jax.Array, k_pages: jax.Array,
                             interpret: bool | None = None) -> jax.Array:
     """Head-parallel :func:`paged_attention` over a ``('pool','heads')`` mesh.
 
-    The kernel grid already iterates ``(batch, kv_heads, pages)`` and every
-    kv head's running softmax is independent, so partitioning axis 2 of the
-    queries and the pools over the mesh's ``'heads'`` axis is embarrassingly
-    parallel: each device runs the *identical* kernel on ``KV / nh`` heads
+    Every kv head's running softmax is independent, so partitioning axis 2
+    of the queries and the pools over the mesh's ``'heads'`` axis is
+    embarrassingly parallel: each device runs the *identical* kernel on ``KV / nh`` heads
     and the results are concatenated. No reduction crosses the shard
     boundary, which is what keeps the sharded output **bitwise identical**
     to the single-device kernel — the contract the serving engine's parity
@@ -231,7 +238,7 @@ def sharded_paged_attention(q: jax.Array, k_pages: jax.Array,
     Page tables and ``pos0`` are scalar-prefetch operands; they stay
     replicated (device-local) on every shard. The ``'pool'`` mesh axis only
     shards storage *at rest* — inside this call all operands are gathered
-    over ``'pool'`` (specs never mention it), and with ``check_rep=False``
+    over ``'pool'`` (specs never mention it), and with ``check_vma=False``
     the identical per-pool-shard outputs collapse back to one.
 
     Falls back to the plain kernel when there is nothing to shard: no mesh,
@@ -252,7 +259,6 @@ def sharded_paged_attention(q: jax.Array, k_pages: jax.Array,
                                v_scale_pages=v_scale_pages,
                                mla_split=mla_split, interpret=interpret)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     sc_spec = P(None, None, 'heads')
@@ -274,9 +280,9 @@ def sharded_paged_attention(q: jax.Array, k_pages: jax.Array,
                                window=window, k_scale_pages=ks,
                                v_scale_pages=vs, interpret=interpret)
 
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(None, None, 'heads', None, None),
-                     check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(None, None, 'heads', None, None),
+                         check_vma=False)(*operands)
 
 
 def dense_page_split(Sc: int, max_page: int = 128) -> int:

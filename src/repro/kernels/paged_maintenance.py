@@ -119,8 +119,11 @@ def _scatter_kernel(jobs_ref, vals_ref, pool_ref, out_ref, *, Sc, fill):
     onehot = ((t_r[:, None] == tt) & written[:, None]).astype(jnp.float32)
     old2 = old.reshape(ps, -1).astype(jnp.float32)
     v2 = v.reshape(T, -1).astype(jnp.float32)
-    # exactly one nonzero per output row -> bitwise the scattered value
-    gathered = jnp.dot(onehot, v2, preferred_element_type=jnp.float32)
+    # exactly one nonzero per output row -> bitwise the scattered value, as
+    # long as the MXU keeps all of fp32: at the default precision the TPU
+    # rounds fp32 operands to bf16, which moves stored positions past 256
+    gathered = jnp.dot(onehot, v2, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
     base = jnp.where(mode >= MODE_FRESH,
                      jnp.full_like(old2, float(fill)), old2)
     out2 = jnp.where(written[:, None], gathered, base)
@@ -134,12 +137,20 @@ def fused_page_write(pool: jax.Array, vals: jax.Array, jobs: jax.Array, *,
     """Apply a :func:`build_jobs` job list to one pool leaf, in place.
 
     pool (NP, ps, ...), vals (B, T, ...) matching trailing dims, jobs
-    (NJ, 5) int32 -> updated pool (donated/aliased: each grid step reads
+    (NJ, 6) int32 -> updated pool (donated/aliased: each grid step reads
     and writes exactly the one page its job names).
     """
+    from repro.kernels.ops import _interpret, on_every_device
     if interpret is None:
-        from repro.kernels.ops import _interpret
         interpret = _interpret()
+    if pool.ndim == 2:
+        # Mosaic tiles a block's last two dims by (8, 128) unless they span
+        # the array, which a (1, ps) page of an (NP, ps) leaf cannot; a
+        # trailing unit axis makes the page block (1, ps, 1), whose last
+        # two dims are the array's own
+        return fused_page_write(pool[..., None], vals[..., None], jobs,
+                                Sc=Sc, fill=fill,
+                                interpret=interpret)[..., 0]
     NJ = jobs.shape[0]
     ps = pool.shape[1]
     T = vals.shape[1]
@@ -160,13 +171,14 @@ def fused_page_write(pool: jax.Array, vals: jax.Array, jobs: jax.Array, *,
                                lambda j, jb, z=zeros: (jb[j, 0], 0) + z),
     )
     kernel = functools.partial(_scatter_kernel, Sc=Sc, fill=fill)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},                         # pool -> out
         interpret=interpret,
-    )(jobs.astype(jnp.int32), vals, pool)
+    )
+    return on_every_device(call, jobs.astype(jnp.int32), vals, pool)
 
 
 def fused_chunk_scatter(cache: dict, vals: dict, pos0: jax.Array,
@@ -224,9 +236,12 @@ def cow_page_copy(pool: jax.Array, sdr: jax.Array, *, fill: int = 0,
     dense gather, no standalone clear dispatch for the tail. Jobs must
     name distinct dst pages (the engine issues one per scan rep).
     """
+    from repro.kernels.ops import _interpret, on_every_device
     if interpret is None:
-        from repro.kernels.ops import _interpret
         interpret = _interpret()
+    if pool.ndim == 2:          # (NP, ps) leaf: see fused_page_write
+        return cow_page_copy(pool[..., None], sdr, fill=fill,
+                             interpret=interpret)[..., 0]
     NJ = sdr.shape[0]
     ps = pool.shape[1]
     tail = pool.shape[2:]
@@ -241,13 +256,14 @@ def cow_page_copy(pool: jax.Array, sdr: jax.Array, *, fill: int = 0,
         out_specs=pl.BlockSpec((1, ps) + tail,
                                lambda j, s, z=zeros: (s[j, 1], 0) + z),
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_cow_kernel, fill=fill),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={1: 0},                         # pool -> out
         interpret=interpret,
-    )(sdr.astype(jnp.int32), pool)
+    )
+    return on_every_device(call, sdr.astype(jnp.int32), pool)
 
 
 def cow_copy_cache(cache: dict, src: jax.Array, dst: jax.Array,

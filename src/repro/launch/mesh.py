@@ -18,10 +18,17 @@ from repro.config import InputShape, ModelConfig
 from repro.sharding import Rules, default_rules
 
 
+def _auto_axes(n: int) -> tuple:
+    """``jax.make_mesh`` makes Explicit axes by default; the sharding
+    constraints of this code base (``with_sharding_constraint``) need the
+    compiler-propagated Auto kind."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ('pod', 'data', 'model') if multi_pod else ('data', 'model')
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def batch_axes(mesh) -> tuple:
@@ -79,7 +86,8 @@ def make_serving_mesh(spec, devices=None):
             f'--xla_force_host_platform_device_count={p * h} before jax '
             f'initialises)')
     return jax.make_mesh((p, h), ('pool', 'heads'),
-                         devices=list(avail)[:p * h])
+                         devices=list(avail)[:p * h],
+                         axis_types=_auto_axes(2))
 
 
 # models big enough that train-mode params/optimizer must be FSDP-sharded

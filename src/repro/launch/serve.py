@@ -8,6 +8,12 @@ engine, with the paper's precomputed first layer ON by default.
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b \
         --prefix-cache --shared-prefix 64 --attn-backend pallas
 
+    # the published widths (seeded random weights) in the config's dtype
+    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --published
+
+JAX's persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
+points, else in ``.jax_cache`` at the root of the checkout.
+
 Failure semantics: every failure is a per-request outcome, never an engine
 crash. Requests move through QUEUED -> PREFILLING -> DECODING -> FINISHED,
 with FAILED / CANCELLED / PREEMPTED branches: malformed submissions fail at
@@ -29,20 +35,57 @@ Both imply ``--telemetry``.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ALL_IDS, get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.models.model import Model
 from repro.serving import Request, ServingEngine
 from repro.serving import telemetry as TM
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed directory, so a
+    later process finds what an earlier one compiled: where
+    ``JAX_COMPILATION_CACHE_DIR`` points (JAX reads it itself), else
+    ``.jax_cache`` at the root of the checkout. Returns the directory."""
+    path = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if not path:
+        path = str(REPO_ROOT / '.jax_cache')
+        jax.config.update('jax_compilation_cache_dir', path)
+    return path
+
+
+def load_model(arch: str, *, published: bool, seed: int):
+    """-> (model, params): the published widths (``get_config``) or the
+    tiny smoke config, with seeded random weights in the config's dtype."""
+    cfg = get_config(arch) if published else get_smoke_config(arch)
+    model = Model(cfg)
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
+def build_engine(model: Model, params, table, **opts) -> ServingEngine:
+    """The serving engine as every entry point builds it: KV state in the
+    config's dtype, layer 0 from the precomputed ``table`` (None = off).
+    ``opts`` are ``ServingEngine`` keyword options."""
+    return ServingEngine(model, params, precomputed=table,
+                         dtype=jnp.dtype(model.cfg.dtype), **opts)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument('--arch', default='gemma3-1b')
+    ap.add_argument('--published', action='store_true',
+                    help='serve the published widths (get_config) instead '
+                         'of the tiny smoke config; weights stay seeded '
+                         'random')
     ap.add_argument('--requests', type=int, default=8)
     ap.add_argument('--slots', type=int, default=4)
     ap.add_argument('--max-slots', type=int, default=0,
@@ -131,12 +174,13 @@ def main() -> None:
                           or args.trace_out)
     if args.mesh:
         _ensure_mesh_devices(args.mesh)
+    use_compile_cache()
 
-    cfg = get_smoke_config(args.arch)
+    model, params = load_model(args.arch, published=args.published,
+                               seed=args.seed)
+    cfg = model.cfg
     if cfg.arch_class in ('audio',):
         raise SystemExit('use examples/whisper_transcribe.py for audio')
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
     table = None
     if not args.no_precompute and cfg.precompute_supported:
         t0 = time.time()
@@ -144,18 +188,18 @@ def main() -> None:
         print(f'precomputed table: {table.table.shape} '
               f'({table.table.size * table.table.dtype.itemsize / 2**20:.1f} '
               f'MiB) built in {time.time() - t0:.2f}s')
-    eng = ServingEngine(model, params,
-                        max_slots=args.max_slots or args.slots,
-                        max_seq=args.max_seq, precomputed=table,
-                        seed=args.seed, chunk_size=args.chunk_size,
-                        fused_gather_rope=args.fused_gather_rope,
-                        prefix_cache=args.prefix_cache,
-                        page_size=args.page_size,
-                        num_pages=args.num_pages or None,
-                        attn_backend=args.attn_backend,
-                        telemetry=want_telemetry,
-                        mesh=args.mesh or None,
-                        async_loop=args.async_loop)
+    eng = build_engine(model, params, table,
+                       max_slots=args.max_slots or args.slots,
+                       max_seq=args.max_seq,
+                       seed=args.seed, chunk_size=args.chunk_size,
+                       fused_gather_rope=args.fused_gather_rope,
+                       prefix_cache=args.prefix_cache,
+                       page_size=args.page_size,
+                       num_pages=args.num_pages or None,
+                       attn_backend=args.attn_backend,
+                       telemetry=want_telemetry,
+                       mesh=args.mesh or None,
+                       async_loop=args.async_loop)
     if eng.mesh is not None:
         sizes = dict(zip(eng.mesh.axis_names, eng.mesh.devices.shape))
         print(f'serving mesh: {sizes["pool"]}x{sizes["heads"]} '

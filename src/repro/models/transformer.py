@@ -552,14 +552,18 @@ def _fused_gather_rope_pre0(precomputed, tokens: jax.Array, pos_t: jax.Array,
 
 
 def pad_table_for_fused(precomputed):
-    """Pad the precomputed table's row width to the Pallas kernels' 128-lane
-    alignment ONCE, so ``ops`` wrappers don't re-pad (copy) the whole table
-    inside every jit'd chunk dispatch. ``split()`` reads only the layout's
-    widths, so trailing pad columns are inert."""
+    """Pad the precomputed table to the gather kernel's tiling ONCE — row
+    width to 128 lanes, vocab to a whole sublane tile — so ``ops`` wrappers
+    don't re-pad (copy) the whole table inside every jit'd chunk dispatch.
+    ``split()`` reads only the layout's widths and token ids never reach the
+    pad rows, so the padding is inert."""
     import dataclasses
-    pad = (-precomputed.table.shape[1]) % 128
-    if pad:
+    from repro.kernels.gather_rope import tile_rows
+    V, W = precomputed.table.shape
+    pad_v = (-V) % tile_rows(precomputed.table.dtype)
+    pad_w = (-W) % 128
+    if pad_v or pad_w:
         precomputed = dataclasses.replace(
             precomputed,
-            table=jnp.pad(precomputed.table, ((0, 0), (0, pad))))
+            table=jnp.pad(precomputed.table, ((0, pad_v), (0, pad_w))))
     return precomputed

@@ -34,10 +34,11 @@ bit-identical to the dense engine.
 **Attention backend** (``attn_backend='auto' | 'reference' | 'pallas'``):
 every attend in the stack routes through ``repro.models.attn_backend``.
 Selection policy — ``'auto'`` (the default) resolves per platform: TPU,
-where the kernels compile, gets ``'pallas'``; CPU/GPU, where they would
-run interpreted (orders of magnitude slower, for validation only), get
-``'reference'``. Passing a concrete name pins the backend regardless of
-platform. The parity contract per backend: ``'reference'`` is the
+where the kernels compile (``tests/test_tpu_compile.py`` compiles them for
+a v5e; ``chip_smoke.py`` serves through them on one), gets ``'pallas'``;
+CPU/GPU, where they would run interpreted (orders of magnitude slower, for
+validation only), get ``'reference'``. Passing a concrete name pins the
+backend regardless of platform. The parity contract per backend: ``'reference'`` is the
 bit-identity oracle (lane-at-a-time rounding, dense-gathered paged
 views) — tokens/logits bit-identical to the dense engine across chunking,
 paging, packing and preempt/resume. ``'pallas'`` runs
@@ -106,12 +107,14 @@ stay bitwise identical to the unsharded engine — a GSPMD-partitioned
 o_proj contraction would reassociate the fp32 reduction and break
 that), and re-constrains outputs to the sharded layout before
 returning (donation-safe). The one genuinely partitioned compute is
-the Pallas paged-attention kernel: its per-(kv head) grid axis is
-embarrassingly parallel, so
+the Pallas paged-attention kernel: its kv heads are independent, so
 ``kernels.paged_attention.sharded_paged_attention`` shard_maps it over
 ``'heads'`` with the page-table / ``pos0`` scalar-prefetch operands
 kept device-local (replicated) — the sharded kernel's output is
-bitwise equal to the unsharded kernel's. Fused in-kernel page
+bitwise equal to the unsharded kernel's. The compiler cannot partition
+a Mosaic kernel, so the step programs are traced under the mesh and
+every other kernel call runs whole on each device inside a
+``shard_map`` (``kernels.ops.on_every_device``). Fused in-kernel page
 maintenance is disabled under a mesh (the job-list kernels assume one
 unpartitioned pool pass); maintenance falls back to the exact XLA
 scatter path. ``max_slots`` scales to the hundreds: host args and
@@ -271,6 +274,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -588,15 +592,20 @@ class ServingEngine:
             self._fresh = jax.tree_util.tree_map(jnp.array, self.states)
         if self.mesh is not None:
             # Shard storage at rest: pool leaves over ('pool', heads over
-            # 'heads'), per-slot leaves over 'pool' on batch. Params and
-            # the reset template are jit arguments -> replicate them
-            # explicitly (`precomputed` is a closure constant; XLA
-            # replicates it on its own).
+            # 'heads'), per-slot leaves over 'pool' on batch. Params, the
+            # precomputed table and the reset template are jit arguments
+            # -> replicate them explicitly.
             rep = jax.sharding.NamedSharding(self.mesh,
                                              jax.sharding.PartitionSpec())
             self.params = jax.device_put(self.params, rep)
+            self.precomputed = jax.device_put(self.precomputed, rep)
             self._fresh = jax.device_put(self._fresh, rep)
             self.states = self._place_states(self.states)
+        # params and the precomputed table ride into every step program as
+        # its first argument: a closed-over array would be embedded in the
+        # program as a constant — a second copy of a vocab-sized table per
+        # compiled program, and a slow compile
+        self._weights = (self.params, self.precomputed)
         self.slot_req: List[Optional[Request]] = [None] * max_slots
         self.slot_pos = np.zeros(max_slots, np.int64)       # next position
         self.slot_next_tok = np.zeros(max_slots, np.int32)  # token to feed
@@ -750,8 +759,23 @@ class ServingEngine:
         return jax.tree_util.tree_map_with_path(one, full, part, mask)
 
     # ----------------------------------------------------------- programs
+    def _jit(self, fn, **kw):
+        """``jax.jit`` of one step program, traced under the serving mesh
+        (if any): the Pallas kernels inside then know to run replicated on
+        every device, which the compiler cannot partition for them
+        (``kernels.ops.on_every_device``)."""
+        if self.mesh is None:
+            return jax.jit(fn, **kw)
+        abstract = self.mesh.abstract_mesh
+
+        @functools.wraps(fn)
+        def on_mesh(*args, **kwargs):
+            with jax.sharding.use_abstract_mesh(abstract):
+                return fn(*args, **kwargs)
+        return jax.jit(on_mesh, **kw)
+
     def _build_programs(self) -> None:
-        model, precomputed = self.model, self.precomputed
+        model = self.model
         sc_ring = self._sc_ring
         backend = self.attn_backend
 
@@ -790,8 +814,9 @@ class ServingEngine:
             flat = flat.at[idx].set(jnp.where(use_prev, pn, 0), mode='drop')
             return flat.reshape(R, T)
 
-        def step(params, states, tokens, pos, key, temps, lane_valid,
+        def step(weights, states, tokens, pos, key, temps, lane_valid,
                  prev_nxt=None, use_prev=None):
+            params, precomputed = weights
             states = self._rep_in(states)
             sub = self._slice_states(states, lane_valid.shape[0])
             tokens = feed_prev(tokens, prev_nxt, use_prev)
@@ -805,10 +830,11 @@ class ServingEngine:
             states = self._merge_states(states, sub, lane_valid.shape[0])
             return self._shard_out(states), nxt, stats['moe_drops'], finite
 
-        self._step = jax.jit(step, donate_argnums=1)
+        self._step = self._jit(step, donate_argnums=1)
 
-        def step_logits(params, states, tokens, pos, key, temps, lane_valid,
+        def step_logits(weights, states, tokens, pos, key, temps, lane_valid,
                         prev_nxt=None, use_prev=None):
+            params, precomputed = weights
             states = self._rep_in(states)
             sub = self._slice_states(states, lane_valid.shape[0])
             tokens = feed_prev(tokens, prev_nxt, use_prev)
@@ -822,10 +848,11 @@ class ServingEngine:
             return self._shard_out(states), nxt, stats['moe_drops'], \
                 finite, logits                                   # (B,1,V)
 
-        self._step_logits = jax.jit(step_logits, donate_argnums=1)
+        self._step_logits = self._jit(step_logits, donate_argnums=1)
 
-        def chunk_hidden(params, states, tokens, pos, n_valid, key, temps,
+        def chunk_hidden(weights, states, tokens, pos, n_valid, key, temps,
                          pt, rt, pending, prev_nxt, use_prev):
+            params, precomputed = weights
             states = self._rep_in(states)
             sub = self._slice_states(states, n_valid.shape[0])
             tokens = feed_prev(tokens, prev_nxt, use_prev)
@@ -845,15 +872,15 @@ class ServingEngine:
             return h, self._shard_out(states), nxt, \
                 stats['moe_drops'], finite
 
-        def chunk_step(params, states, tokens, pos, n_valid, key, temps,
+        def chunk_step(weights, states, tokens, pos, n_valid, key, temps,
                        pt=None, rt=None, pending=None, prev_nxt=None,
                        use_prev=None):
             _, states, nxt, drops, finite = chunk_hidden(
-                params, states, tokens, pos, n_valid, key, temps, pt, rt,
+                weights, states, tokens, pos, n_valid, key, temps, pt, rt,
                 pending, prev_nxt, use_prev)
             return states, nxt, drops, finite
 
-        def chunk_step_logits(params, states, tokens, pos, n_valid, key,
+        def chunk_step_logits(weights, states, tokens, pos, n_valid, key,
                               temps, pt=None, rt=None, pending=None,
                               prev_nxt=None, use_prev=None):
             # logits-on-demand: same sampled-token program as chunk_step
@@ -861,24 +888,27 @@ class ServingEngine:
             # prompt scoring — padding lanes (t >= n_valid) are garbage and
             # dropped host-side.
             h, states, nxt, drops, finite = chunk_hidden(
-                params, states, tokens, pos, n_valid, key, temps, pt, rt,
+                weights, states, tokens, pos, n_valid, key, temps, pt, rt,
                 pending, prev_nxt, use_prev)
-            return states, nxt, drops, finite, lm_logits(params, h, model.cfg)
+            return states, nxt, drops, finite, \
+                lm_logits(weights[0], h, model.cfg)
 
         # paged mode always runs the chunk-shaped program (its T == 1 case
         # is bit-identical to the single-token step), so a paged engine
         # needs the chunk jits even at chunk_size == 1
         want_chunk = self.chunk_size > 1 or self.paged
-        self._chunk_step = jax.jit(chunk_step, donate_argnums=1) \
+        self._chunk_step = self._jit(chunk_step, donate_argnums=1) \
             if want_chunk else None
-        self._chunk_step_logits = jax.jit(chunk_step_logits, donate_argnums=1) \
+        self._chunk_step_logits = \
+            self._jit(chunk_step_logits, donate_argnums=1) \
             if want_chunk else None
 
-        def packed_hidden(params, states, tokens, pos, n_valid, packed, key,
+        def packed_hidden(weights, states, tokens, pos, n_valid, packed, key,
                           temps, pt, rt, pending, prev_nxt, use_prev):
             # segment-packed prefill: tokens is the bin-packed (R, T) grid,
             # pos/n_valid/states stay slot-major (S,). Each slot's last
             # valid hidden lives at lane (seg_row, seg_off + n_valid - 1).
+            params, precomputed = weights
             states = self._rep_in(states)
             sub = self._slice_states(states, n_valid.shape[0])
             tokens = feed_prev(tokens, prev_nxt, use_prev, packed)
@@ -900,29 +930,29 @@ class ServingEngine:
             return h, self._shard_out(states), nxt, \
                 stats['moe_drops'], finite
 
-        def packed_step(params, states, tokens, pos, n_valid, packed, key,
+        def packed_step(weights, states, tokens, pos, n_valid, packed, key,
                         temps, pt=None, rt=None, pending=None,
                         prev_nxt=None, use_prev=None):
             _, states, nxt, drops, finite = packed_hidden(
-                params, states, tokens, pos, n_valid, packed, key, temps,
+                weights, states, tokens, pos, n_valid, packed, key, temps,
                 pt, rt, pending, prev_nxt, use_prev)
             return states, nxt, drops, finite
 
-        def packed_step_logits(params, states, tokens, pos, n_valid, packed,
+        def packed_step_logits(weights, states, tokens, pos, n_valid, packed,
                                key, temps, pt=None, rt=None, pending=None,
                                prev_nxt=None, use_prev=None):
             # packed scoring: the lm_head on every packed lane — slot s's
             # prompt logits live at row seg_row[s], cols seg_off[s]..+n_valid
             h, states, nxt, drops, finite = packed_hidden(
-                params, states, tokens, pos, n_valid, packed, key, temps,
+                weights, states, tokens, pos, n_valid, packed, key, temps,
                 pt, rt, pending, prev_nxt, use_prev)
             return states, nxt, drops, finite, \
-                lm_logits(params, h, model.cfg)
+                lm_logits(weights[0], h, model.cfg)
 
-        self._packed_step = jax.jit(packed_step, donate_argnums=1) \
+        self._packed_step = self._jit(packed_step, donate_argnums=1) \
             if self.pack_prefill else None
         self._packed_step_logits = \
-            jax.jit(packed_step_logits, donate_argnums=1) \
+            self._jit(packed_step_logits, donate_argnums=1) \
             if self.pack_prefill else None
 
         mask = self._paged_mask
@@ -1821,7 +1851,7 @@ class ServingEngine:
                     self._pack_layout(tokens, n_valid)
                 self.lanes_dispatched += int(ptoks.size)
                 self.lane_tokens += int(n_valid.sum())
-                args = [self.params, self.states, jnp.asarray(ptoks), pos,
+                args = [self._weights, self.states, jnp.asarray(ptoks), pos,
                         jnp.asarray(n_valid), playout, sub, temps]
                 if self.paged:
                     args += [jnp.asarray(self._pt[:nb]),
@@ -1839,7 +1869,7 @@ class ServingEngine:
             else:
                 self.lanes_dispatched += int(tokens.size)
                 self.lane_tokens += int(n_valid.sum())
-                args = [self.params, self.states, jnp.asarray(tokens), pos,
+                args = [self._weights, self.states, jnp.asarray(tokens), pos,
                         jnp.asarray(n_valid), sub, temps]
                 if self.paged:
                     args += [jnp.asarray(self._pt[:nb]),
@@ -1876,7 +1906,7 @@ class ServingEngine:
             lv = np.zeros(nb, bool)
             lv[active] = True
             lane_valid = jnp.asarray(lv)
-            args = (self.params, self.states, tokens, pos, sub, temps,
+            args = (self._weights, self.states, tokens, pos, sub, temps,
                     lane_valid)
             kw = {}
             if pend is not None:

@@ -16,9 +16,10 @@ cache contents bitwise equal to eager clear + XLA scatter, including
 non-page-multiple ring lengths whose last page is partial.
 
 Tests marked ``compiled`` resolve ``interpret`` by platform
-(``kernels.ops._interpret``): on TPU the kernels compile for real; on CPU
-CI they fall back to interpret mode, so the same assertions pin both
-worlds (``pytest -m compiled``).
+(``kernels.ops._interpret``): on CPU they run the kernels interpreted; run
+on a TPU, the same assertions would hold the compiled kernels (not done
+yet). Whether the kernels compile for a TPU at all is checked by
+``tests/test_tpu_compile.py``.
 """
 import jax
 import jax.numpy as jnp
@@ -181,7 +182,14 @@ def test_model_chunked_decode_parity_dense(kind, quant):
             logits.append(lg[:, :n])
             p += n
         outs[backend] = np.asarray(jnp.concatenate(logits, 1))
-    np.testing.assert_allclose(outs['pallas'], outs['reference'], **TOL)
+    tol = dict(TOL)
+    if quant:
+        # An fp32-ulp difference in one layer's output can flip the rounding
+        # of a cached int8 value in the next layer, moving it by one
+        # quantisation step (1/127 of its row's max-abs) — and the logits by
+        # up to about one step of their own scale.
+        tol['atol'] += np.abs(outs['reference']).std() / 127
+    np.testing.assert_allclose(outs['pallas'], outs['reference'], **tol)
 
 
 # ============================================================= engine parity
@@ -362,10 +370,10 @@ def test_cow_page_copy_bitwise():
 @pytest.mark.compiled
 @pytest.mark.parametrize('quant', [False, True])
 def test_compiled_attend_matches_oracle_within_bound(quant):
-    """Platform-default compile (interpret=None -> ops._interpret): the
-    paged attend holds the documented PALLAS_TOL bound vs the gather
-    oracle. On TPU this is the compiled kernel the engine's 'auto' backend
-    serves with; CPU CI exercises the same assertions in interpret mode."""
+    """Platform-default mode (interpret=None -> ops._interpret): the paged
+    attend holds the documented PALLAS_TOL bound vs the gather oracle.
+    Interpreted on CPU; on a TPU it would be the compiled kernel the
+    engine's 'auto' backend serves with."""
     B, KV, G, d, T, ps, window = 2, 2, 2, 16, 4, 8, 5
     Sc = 11
     P = -(-Sc // ps)
