@@ -1,0 +1,9 @@
+"""Share of its roofline the paged-attention kernel reaches: the least
+time its useful work needs (valid context only, bench/work.py) over its
+device time in the trace, in %."""
+from bench import work
+from bench.readers import PAGED_ATTENTION, kernel_roofline
+
+
+def read(rec):
+    return kernel_roofline(rec, PAGED_ATTENTION, work.paged_attention_work)
