@@ -125,6 +125,7 @@ def gather_rope(table: jax.Array, ids: jax.Array, positions: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Np, W), table.dtype),
         interpret=interpret,
+        name='gather_rope',
     )
     out = on_every_device(call, ids, positions,
                           _lane_constants(W, segs, theta), table)

@@ -209,6 +209,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, dv), q.dtype),
         interpret=interpret,
+        name='paged_attention',
     )
     out = on_every_device(call, pos0.astype(jnp.int32),
                           table.astype(jnp.int32), *operands)

@@ -177,6 +177,7 @@ def fused_page_write(pool: jax.Array, vals: jax.Array, jobs: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},                         # pool -> out
         interpret=interpret,
+        name='fused_page_write',
     )
     return on_every_device(call, jobs.astype(jnp.int32), vals, pool)
 
@@ -262,6 +263,7 @@ def cow_page_copy(pool: jax.Array, sdr: jax.Array, *, fill: int = 0,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={1: 0},                         # pool -> out
         interpret=interpret,
+        name='cow_page_copy',
     )
     return on_every_device(call, sdr.astype(jnp.int32), pool)
 

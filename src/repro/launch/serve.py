@@ -26,7 +26,9 @@ non-finite logits fail only the offending lane. ``run()`` reports
 preemptions / failed / cancelled / deadline_exceeded, printed below.
 
 Observability: ``--telemetry`` turns on the engine's metrics registry and
-per-request span tracer (host-side only, tokens stay bit-identical);
+per-request span tracer (host-side only, tokens stay bit-identical), and
+the engine's step phases as ``jax.profiler`` annotations (``engine.*``),
+which a profiler capture shows beside the device ops;
 ``--metrics-out FILE`` dumps the registry as Prometheus text (``.prom``/
 ``.txt``) or structured JSON, and ``--trace-out FILE`` writes a
 Chrome-trace-format span export (load in ``chrome://tracing`` or Perfetto).
@@ -159,8 +161,10 @@ def main() -> None:
                          'freed, the rest keep serving (0 = no deadline)')
     ap.add_argument('--telemetry', action='store_true',
                     help='enable the metrics registry + per-request span '
-                         'tracer (host-side; tokens stay bit-identical). '
-                         'Implied by --metrics-out / --trace-out')
+                         'tracer, and the step phases as profiler '
+                         'annotations (host-side; tokens stay '
+                         'bit-identical). Implied by --metrics-out / '
+                         '--trace-out')
     ap.add_argument('--metrics-out', default='',
                     help='write the metrics registry to this file: '
                          'Prometheus exposition text for .prom/.txt, '

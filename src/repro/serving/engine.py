@@ -239,6 +239,18 @@ lanes consume prompt tokens while others decode):
   belongs to the *previous* dispatch (one-step pipeline), and is still
   charged to that dispatch's ``kind``.
 
+**Profiler spans.** The same intervals are also ``jax.profiler``
+annotations (:meth:`repro.serving.telemetry.Telemetry.span`), so a
+profiler capture of a telemetry-on engine shows each phase beside the
+device ops it waits on or enqueues: ``engine.step`` (attr ``step``)
+around :meth:`step_once`, holding ``engine.host_schedule`` (with one
+``engine.radix_lookup`` per lookup nested in it), ``engine.pack_layout``,
+``engine.dispatch`` (``step``, ``kind``, ``T``, ``nb``) and
+``engine.sample_commit`` (``step`` of the dispatch it commits). Inside
+``sample_commit``, ``engine.device_wait`` covers only the ``np.asarray``
+transfers, so ``sample_commit`` minus ``device_wait`` is the host's
+commit work. A telemetry-off engine opens no annotation.
+
 Telemetry-enabled engines also register ``engine.queue.depth`` (a
 callback gauge: requests waiting for a slot at scrape time), and async
 engines the ``engine.step.overlap_s`` histogram (keyed by ``backend``):
@@ -272,6 +284,7 @@ single ``enabled`` bool — no clock reads, no per-step allocation.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import functools
@@ -290,6 +303,8 @@ from repro.serving import telemetry as TM
 from repro.serving.faults import FaultInjector
 from repro.serving.kvpool import PrefixCache
 from repro.serving.sampler import sample_tokens
+
+_NO_SPAN = contextlib.nullcontext()     # device_wait's stand-in, telemetry off
 
 
 class RequestStatus(str, enum.Enum):
@@ -487,6 +502,7 @@ class ServingEngine:
             self._phase_h = None
             self._overlap_h = None
         self._t_radix = 0.0     # radix-lookup seconds within current step
+        self._phase_span = None  # open profiler span of the current phase
         if model.cfg.arch_class == 'audio':
             chunk_size = 1   # enc-dec decode is one token per step by API
             if prefix_cache:
@@ -1290,10 +1306,11 @@ class ServingEngine:
         node, nblocks, pages = None, 0, []
         if not req.return_logits and P > 1:
             if self.telemetry.enabled:
-                _r0 = self.telemetry.now()
-                res = self.kv.match(prompt, max_tokens=P - 1,
-                                    need_snapshot=self._needs_snapshot)
-                self._t_radix += self.telemetry.now() - _r0
+                with self.telemetry.span(TM.SPAN_PHASE['radix_lookup']):
+                    _r0 = self.telemetry.now()
+                    res = self.kv.match(prompt, max_tokens=P - 1,
+                                        need_snapshot=self._needs_snapshot)
+                    self._t_radix += self.telemetry.now() - _r0
             else:
                 res = self.kv.match(prompt, max_tokens=P - 1,
                                     need_snapshot=self._needs_snapshot)
@@ -1691,6 +1708,9 @@ class ServingEngine:
         obs = tel.enabled
         if obs:
             self._t_radix = 0.0
+            step_span = tel.span(TM.SPAN_STEP, step=self.steps)
+            step_span.__enter__()
+            self._phase_to('host_schedule')
         _t0 = tel.now() if obs else 0.0
         if self.fault_injector is not None:
             self.fault_injector.before_step(self)
@@ -1702,12 +1722,26 @@ class ServingEngine:
             self._flush_async()
         self._admit()
         rec = self._schedule_dispatch(_t0, obs)
+        if obs:
+            self._phase_to(None)    # host_schedule, if nothing was dispatched
         if self.async_loop:
             prev, self._pending = self._pending, rec
             if prev is not None:
                 self._commit(prev)
         elif rec is not None:
             self._commit(rec)
+        if obs:
+            step_span.__exit__(None, None, None)
+
+    def _phase_to(self, phase: Optional[str], **attrs) -> None:
+        """Close the open phase's profiler span; open ``phase``'s."""
+        if self._phase_span is not None:
+            self._phase_span.__exit__(None, None, None)
+            self._phase_span = None
+        if phase is not None:
+            self._phase_span = self.telemetry.span(TM.SPAN_PHASE[phase],
+                                                   **attrs)
+            self._phase_span.__enter__()
 
     def _flush_async(self) -> None:
         """Drain the async pipeline: commit the pending dispatch (if any).
@@ -1836,6 +1870,7 @@ class ServingEngine:
                 kind = ('mixed' if 0 < n_pre < len(active)
                         else ('prefill' if n_pre else 'decode'))
                 _t1 = tel.now()
+                self._phase_to('pack_layout')
             nb = self._bucket(active)
             tokens, n_valid = tokens[:nb], n_valid[:nb]
             temps = jnp.asarray([
@@ -1859,6 +1894,8 @@ class ServingEngine:
                              self._pending_array()]
                 if obs:
                     _t2 = tel.now()
+                    self._phase_to('dispatch', step=step_idx, kind=kind,
+                                   T=int(ptoks.shape[1]), nb=nb)
                 if want_logits:
                     self.states, nxt, drops, finite, logits = \
                         self._packed_step_logits(*args, **kw)
@@ -1877,6 +1914,8 @@ class ServingEngine:
                              self._pending_array()]
                 if obs:
                     _t2 = tel.now()
+                    self._phase_to('dispatch', step=step_idx, kind=kind,
+                                   T=int(tokens.shape[1]), nb=nb)
                 if want_logits:
                     self.states, nxt, drops, finite, logits = \
                         self._chunk_step_logits(*args, **kw)
@@ -1897,6 +1936,7 @@ class ServingEngine:
                 kind = ('mixed' if 0 < n_pre < len(active)
                         else ('prefill' if n_pre else 'decode'))
                 _t1 = tel.now()
+                self._phase_to('pack_layout')
             nb = self._bucket(active)
             temps = jnp.asarray([
                 (self.slot_req[s].temperature if self.slot_req[s] else 0.0)
@@ -1914,6 +1954,8 @@ class ServingEngine:
                           use_prev=jnp.asarray(use_prev[:nb]))
             if obs:
                 _t2 = tel.now()
+                self._phase_to('dispatch', step=step_idx, kind=kind, T=1,
+                               nb=nb)
             if want_logits:
                 self.states, nxt, drops, finite, logits = \
                     self._step_logits(*args, **kw)
@@ -1924,6 +1966,7 @@ class ServingEngine:
 
         if obs:
             _t3 = tel.now()
+            self._phase_to(None)
         # advance host scheduling state NOW (dispatch time): async mode
         # schedules the next step from these positions before the commit
         # lands. Everything the deferred commit needs is recorded per lane.
@@ -1970,15 +2013,22 @@ class ServingEngine:
         pages freed after the in-order device writes)."""
         tel = self.telemetry
         obs = tel.enabled and rec.kind is not None
+        if obs:
+            commit_span = tel.span(TM.SPAN_PHASE['sample_commit'],
+                                   step=rec.step_idx)
+            commit_span.__enter__()
         _t3 = tel.now() if obs else 0.0
-        nxt = np.asarray(rec.nxt)
-        bad = ~np.asarray(rec.finite)
+        with (tel.span(TM.SPAN_DEVICE_WAIT, step=rec.step_idx) if obs
+              else _NO_SPAN):
+            nxt = np.asarray(rec.nxt)
+            bad = ~np.asarray(rec.finite)
+            drops = int(rec.drops)
+            logits = None if rec.logits is None else np.asarray(rec.logits)
         if self.fault_injector is not None:
             for s in self.fault_injector.poison_lanes(self, rec.step_idx):
                 if 0 <= s < len(bad):
                     bad[s] = True
-        self.moe_token_drops += int(rec.drops)
-        logits = None if rec.logits is None else np.asarray(rec.logits)
+        self.moe_token_drops += drops
         for ln in rec.lanes:
             s, req = ln.slot, ln.req
             if self.slot_req[s] is not req \
@@ -2046,6 +2096,7 @@ class ServingEngine:
             ph['pack_layout'].observe(pk)
             ph['dispatch'].observe(dp)
             ph['sample_commit'].observe(_t4 - _t3)
+            commit_span.__exit__(None, None, None)
 
     def run(self, max_iters: int = 100_000) -> Dict[str, int]:
         """Drive the engine until all submitted work reaches a terminal

@@ -20,7 +20,9 @@ module is the measurement substrate everything else reports through:
   formats: :meth:`Telemetry.snapshot` (structured dict → JSON),
   :meth:`Telemetry.prometheus_text` (Prometheus exposition text), and
   :meth:`Telemetry.chrome_trace` (Chrome ``chrome://tracing`` / Perfetto
-  JSON of the request spans).
+  JSON of the request spans). :meth:`Telemetry.span` opens a JAX profiler
+  annotation: the engine's step phases (the ``SPAN_*`` names) land in a
+  ``jax.profiler`` capture beside the device ops, on the device's clock.
 
 **Zero-cost when disabled.** The engine holds :data:`NULL_TELEMETRY` (a
 :class:`NullTelemetry` singleton, ``enabled = False``) unless telemetry
@@ -31,7 +33,8 @@ touches jit'd code or inserts device sync points: phase stamps wrap
 host-side code only, so the ``dispatch`` phase measures the host cost of
 enqueueing the jitted step (XLA dispatch is async) and ``sample_commit``
 absorbs the device wait at the host-transfer boundary that the engine
-performs anyway. Every bit-identity contract is preserved — telemetry-on
+performs anyway (the ``engine.device_wait`` profiler span covers just
+that transfer). Every bit-identity contract is preserved — telemetry-on
 tokens are bitwise telemetry-off tokens (``tests/test_telemetry.py``).
 
 Metric and trace-event **names are defined here, once** (the ``KV_*``,
@@ -47,6 +50,8 @@ import json
 import time
 from bisect import bisect_left
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
 
 # --------------------------------------------------------------------------
 # Metric names — the single source of truth. kvpool.stats() builds its dict
@@ -76,6 +81,16 @@ STEP_PHASE = 'engine.step.phase_s'
 PHASES = ('host_schedule', 'radix_lookup', 'pack_layout', 'dispatch',
           'sample_commit')
 STEP_KINDS = ('prefill', 'decode', 'mixed')
+
+# Profiler spans (Telemetry.span) over the same intervals as the phases.
+# Nesting: engine.step > engine.host_schedule > engine.radix_lookup (one
+# per lookup), engine.step > engine.pack_layout, engine.dispatch (attrs
+# step, kind, T, nb), engine.sample_commit (step it commits: under the
+# async loop the previous one) > engine.device_wait (the np.asarray
+# transfers only; sample_commit's self time is the host's commit work).
+SPAN_STEP = 'engine.step'
+SPAN_DEVICE_WAIT = 'engine.device_wait'
+SPAN_PHASE = {ph: f'engine.{ph}' for ph in PHASES}
 
 # engine.step.overlap_s{backend=} — host-side scheduling work (the
 # host_schedule + radix_lookup + pack_layout phases of step N+1) performed
@@ -372,6 +387,15 @@ class Telemetry:
     def event(self, uid: Optional[int], name: str,
               t: Optional[float] = None, **attrs) -> None:
         self.tracer.event(uid, name, t=t, **attrs)
+
+    @staticmethod
+    def span(name: str, **attrs) -> jax.profiler.TraceAnnotation:
+        """A ``jax.profiler.TraceAnnotation`` named ``name`` whose
+        ``attrs`` become the event's stats in a profiler capture: a context
+        manager, or ``__enter__``/``__exit__`` across a phase boundary.
+        Outside a capture it records nothing and costs about a
+        microsecond."""
+        return jax.profiler.TraceAnnotation(name, **attrs)
 
     # ------------------------------------------------------------- exports
     def snapshot(self) -> Dict[str, Any]:

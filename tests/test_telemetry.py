@@ -18,8 +18,14 @@ disabled mode.
 - **Single-source metric names**: ``kvpool.stats()`` keys are the
   ``KV_*`` constants and the registry gauges mirror them after
   ``bind_telemetry``.
+- **Profiler spans**: a telemetry-on engine run under ``jax.profiler``
+  leaves every ``engine.*`` phase span, nested and with its attributes,
+  one ``engine.dispatch`` per step, and span durations that add up to
+  the ``engine.step.phase_s`` totals.
 """
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -61,11 +67,11 @@ def _reqs(n=5, new_tokens=8, temp=0.0):
 
 
 def _engine(telemetry, *, paged=True, pack=False, faults=None,
-            num_pages=24):
+            num_pages=24, async_loop=False):
     model, params = _build()
     kw = dict(max_slots=4, max_seq=MAX_SEQ, chunk_size=4,
               fault_injector=faults, telemetry=telemetry,
-              pack_prefill=pack)
+              pack_prefill=pack, async_loop=async_loop)
     if paged:
         kw.update(prefix_cache=True, page_size=PS, num_pages=num_pages)
     return ServingEngine(model, params, **kw)
@@ -195,6 +201,88 @@ def test_kvpool_stats_keys_single_source():
     for key in (TM.KV_PAGES_IN_USE, TM.KV_PAGES_FREE,
                 TM.KV_PAGES_RECLAIMABLE, TM.KV_EVICTIONS):
         assert gauges[key] == st[key], key
+
+
+# ------------------------------------------------------------ profiler spans
+def _profiled_run(log_dir, async_loop):
+    """A telemetry-on engine run under the JAX profiler; -> (engine, its
+    ``engine.*`` host spans as (name, start_ns, end_ns, attrs))."""
+    eng = _engine(True, async_loop=async_loop)
+    for r in _reqs():
+        eng.submit(r)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(log_dir, 'plugins', 'profile', '*',
+                                   '*.xplane.pb'))
+    spans = [(ev.name, int(ev.start_ns),
+              int(ev.start_ns) + int(ev.duration_ns), dict(ev.stats))
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith('/host:')
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith('engine.')]
+    return eng, spans
+
+
+@pytest.mark.parametrize('async_loop', [False, True],
+                         ids=['sync', 'async'])
+def test_phase_spans_in_profiler_trace(tmp_path, async_loop):
+    eng, spans = _profiled_run(str(tmp_path), async_loop)
+    by = {}
+    for sp in spans:
+        by.setdefault(sp[0], []).append(sp)
+    assert set(by) == {TM.SPAN_STEP, TM.SPAN_DEVICE_WAIT,
+                       *TM.SPAN_PHASE.values()}
+
+    def parent(sp, name):
+        """The span named ``name`` that holds ``sp`` (exactly one)."""
+        out = [p for p in by[name] if p[1] <= sp[1] and sp[2] <= p[2]]
+        assert len(out) == 1, (sp, name, out)
+        return out[0]
+
+    disp = by[TM.SPAN_PHASE['dispatch']]
+    assert [d[3]['step'] for d in disp] == list(range(eng.steps))
+    for d in disp:
+        assert d[3]['kind'] in TM.STEP_KINDS
+        assert d[3]['T'] in (1, eng.chunk_size) and d[3]['nb'] >= 1
+        assert parent(d, TM.SPAN_STEP)[3]['step'] == d[3]['step']
+    for ph in ('host_schedule', 'pack_layout'):
+        for sp in by[TM.SPAN_PHASE[ph]]:
+            parent(sp, TM.SPAN_STEP)
+    for sp in by[TM.SPAN_PHASE['radix_lookup']]:
+        parent(sp, TM.SPAN_PHASE['host_schedule'])
+    lag = 1 if async_loop else 0
+    commits = by[TM.SPAN_PHASE['sample_commit']]
+    assert sorted(c[3]['step'] for c in commits) == list(range(eng.steps))
+    for c in commits:
+        held = [p for p in by[TM.SPAN_STEP] if p[1] <= c[1] and c[2] <= p[2]]
+        if held:        # the async loop's last commit drains after a step
+            assert held[0][3]['step'] == c[3]['step'] + lag
+    for w in by[TM.SPAN_DEVICE_WAIT]:
+        assert parent(w, TM.SPAN_PHASE['sample_commit'])[3]['step'] == \
+            w[3]['step']
+
+    # the spans measure what the phase histograms measure; host_schedule
+    # by its self time (radix_lookup taken out), in steps that dispatched
+    dur = lambda sps: sum(e - s for _, s, e, _ in sps) / 1e9
+    steps = [p for p in by[TM.SPAN_STEP]
+             if any(p[1] <= d[1] < p[2] for d in disp)]
+    inside = lambda name: [sp for sp in by[TM.SPAN_PHASE[name]]
+                           if any(p[1] <= sp[1] < p[2] for p in steps)]
+    got = {ph: dur(inside(ph)) for ph in ('radix_lookup', 'pack_layout',
+                                          'dispatch')}
+    got['host_schedule'] = dur(inside('host_schedule')) - got['radix_lookup']
+    got['sample_commit'] = dur(commits)
+    want = {ph: 0.0 for ph in TM.PHASES}
+    for labels, h in eng.telemetry.registry.find(TM.STEP_PHASE).items():
+        want[dict(labels)['phase']] += h.total
+    for ph in TM.PHASES:
+        assert abs(got[ph] - want[ph]) <= 0.05 * want[ph] + 1e-3, \
+            (ph, got[ph], want[ph])
 
 
 # ------------------------------------------------------------- chaos traces
