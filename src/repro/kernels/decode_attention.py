@@ -25,6 +25,12 @@ from repro.kernels.paged_attention import (NEG_INF, dense_as_pages,
 
 __all__ = ['decode_attention', 'page_validity', 'NEG_INF']
 
+# A window no query reaches past: it masks nothing more than window 0, and
+# it keeps the kernel's walk over every block, which a cache holding any
+# position at any index needs (the kernel ends a window-0 walk at the last
+# block a linear cache can hold the query's history in).
+_WHOLE_CACHE = 2 ** 30
+
 
 @functools.partial(jax.jit, static_argnames=('window', 'block_s',
                                              'interpret'))
@@ -46,5 +52,5 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         dense_as_pages(cache_pos, bs),
         dense_identity_table(B, Sc, bs),
         pos.astype(jnp.int32),
-        scale=d ** -0.5, window=window, interpret=interpret)
+        scale=d ** -0.5, window=window or _WHOLE_CACHE, interpret=interpret)
     return out.reshape(B, H, d)

@@ -1,13 +1,25 @@
 """Pallas TPU kernel: in-place paged/chunked decode attention.
 
-The serving engine's decode hot loop is memory-bandwidth-bound: every step
-streams the whole KV working set HBM->VMEM once. The reference paged path
-pays that twice — it first *gathers* each slot's pages into a dense-shaped
-``(B, Sc, ...)`` virtual cache per layer per step, then attends over the
-copy. This kernel removes the copy: the grid runs over ``(batch, pages)``
-and each step DMAs ONE physical page of the global pool (all its kv heads)
-straight into VMEM through the per-slot page table (the table is
-scalar-prefetched, so page ``j``'s DMA is issued before the body runs).
+The reference paged path first *gathers* each slot's pages into a
+dense-shaped ``(B, Sc, ...)`` virtual cache per layer per step, then attends
+over the copy. This kernel removes the copy: the grid runs over
+``(batch, pages)`` and each step DMAs ONE physical page of the global pool
+(all its kv heads) straight into VMEM through the per-slot page table (the
+table is scalar-prefetched, so page ``j``'s DMA is issued before the body
+runs).
+
+Each slot's walk ends at its last reachable page on full-attention layers
+(``window == 0``). Their table is linear — block ``j`` holds positions
+``[j*ps, (j+1)*ps)`` or none — so no query of a chunk at ``pos0 .. pos0 +
+T - 1`` attends past block ``min((pos0 + T - 1) // ps, P - 1)``. Grid steps
+past it skip the body: their pages hold only later positions or ``-1``,
+which would leave the running softmax exactly as it was, so the output is
+bitwise that of the full walk. The index maps stay ``table[b, j]``: the
+serving pool's tables hold the null page past a slot's allocation, whose
+repeated block index issues no DMA, and clamping ``j`` in every map cost
+~0.1 µs a grid step on a TPU v5e, more than the DMAs it would save.
+Sliding-window layers read ring tables, whose blocks map to no fixed
+positions, and walk every page.
 
 It also removes the reference path's query-lane serialisation: all T query
 lanes of a prefill chunk are batched into a single dispatch (one
@@ -61,8 +73,8 @@ def _nt_dot(a: jax.Array, b: jax.Array) -> jax.Array:
                                preferred_element_type=jnp.float32)
 
 
-def _paged_kernel(pos0_ref, table_ref, roff_ref, q_ref, *refs, n_j, window,
-                  scale, quant, mla_split):
+def _paged_kernel(pos0_ref, table_ref, roff_ref, q_ref, *refs, n_j, T, ps,
+                  window, scale, quant, mla_split):
     refs = list(refs)
     k_ref = refs.pop(0)
     if mla_split:
@@ -85,37 +97,42 @@ def _paged_kernel(pos0_ref, table_ref, roff_ref, q_ref, *refs, n_j, window,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    qpos = pos0_ref[b] + roff_ref[...]                      # (R, 1)
-    valid = page_validity(cpos_ref[0], qpos, window)        # (R, ps)
-    if quant:                                   # (ps, KV) -> (KV, ps) rows
-        ks_rows = ks_ref[0].astype(jnp.float32).T
-        vs_rows = vs_ref[0].astype(jnp.float32).T
+    # linear tables: a block whose first position is past the chunk's last
+    # query is past the slot's last reachable page; the scratch stays as is
+    @pl.when(True if window else j * ps <= pos0_ref[b] + T - 1)
+    def _attend():
+        qpos = pos0_ref[b] + roff_ref[...]                      # (R, 1)
+        valid = page_validity(cpos_ref[0], qpos, window)        # (R, ps)
+        if quant:                                   # (ps, KV) -> (KV, ps) rows
+            ks_rows = ks_ref[0].astype(jnp.float32).T
+            vs_rows = vs_ref[0].astype(jnp.float32).T
 
-    for h in range(q_ref.shape[1]):       # every kv head of this page
-        q = q_ref[0, h].astype(jnp.float32)                 # (R, dq)
-        if mla_split:
-            k1 = k_ref[0, :, h].astype(jnp.float32)         # (ps, r)
-            k2 = k2_ref[0, :, h].astype(jnp.float32)        # (ps, dr)
-            s = _nt_dot(q[:, :mla_split], k1) + _nt_dot(q[:, mla_split:], k2)
-            v = k1
-        else:
-            k = k_ref[0, :, h].astype(jnp.float32)          # (ps, dk)
-            v = v_ref[0, :, h].astype(jnp.float32)          # (ps, dv)
-            s = _nt_dot(q, k)
-            if quant:           # per-token scales on the scores
-                s = s * ks_rows[h:h + 1]
-        s = jnp.where(valid, s * scale, NEG_INF)
+        for h in range(q_ref.shape[1]):       # every kv head of this page
+            q = q_ref[0, h].astype(jnp.float32)                 # (R, dq)
+            if mla_split:
+                k1 = k_ref[0, :, h].astype(jnp.float32)         # (ps, r)
+                k2 = k2_ref[0, :, h].astype(jnp.float32)        # (ps, dr)
+                s = (_nt_dot(q[:, :mla_split], k1)
+                     + _nt_dot(q[:, mla_split:], k2))
+                v = k1
+            else:
+                k = k_ref[0, :, h].astype(jnp.float32)          # (ps, dk)
+                v = v_ref[0, :, h].astype(jnp.float32)          # (ps, dv)
+                s = _nt_dot(q, k)
+                if quant:           # per-token scales on the scores
+                    s = s * ks_rows[h:h + 1]
+            s = jnp.where(valid, s * scale, NEG_INF)
 
-        m_prev = m_scr[h]                                   # (R, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:               # ... and on the value mix
-            p = p * vs_rows[h:h + 1]
-        acc_scr[h] = acc_scr[h] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[h] = m_new
+            m_prev = m_scr[h]                                   # (R, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if quant:               # ... and on the value mix
+                p = p * vs_rows[h:h + 1]
+            acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(j == n_j - 1)
     def _finalize():
@@ -147,7 +164,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
     ``[q_abs (r) | q_pe (dr)]``, ``k2_pages`` holds the kpe pool and the
     value mix reads ``k_pages`` (ckv) again. ``k/v_scale_pages``
     (NP, ps, KV) enable the int8 pool. The kernel never materialises a
-    gathered cache: page ``table[b, j]`` is read in place on grid step j.
+    gathered cache: page ``table[b, j]`` is read in place on grid step j,
+    and attended up to the slot's last reachable block when
+    ``window == 0``.
 
     Mosaic tiles the last two dims of every block by (8, 128) unless they
     span the whole array, so each grid step takes ALL kv heads of a page
@@ -201,7 +220,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
             pltpu.VMEM((KV, R, dv), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_kernel, n_j=P, window=window,
+    kernel = functools.partial(_paged_kernel, n_j=P, T=T, ps=ps,
+                               window=window,
                                scale=float(scale), quant=quant,
                                mla_split=mla_split)
     call = pl.pallas_call(

@@ -245,7 +245,9 @@ profiler capture of a telemetry-on engine shows each phase beside the
 device ops it waits on or enqueues: ``engine.step`` (attr ``step``)
 around :meth:`step_once`, holding ``engine.host_schedule`` (with one
 ``engine.radix_lookup`` per lookup nested in it), ``engine.pack_layout``,
-``engine.dispatch`` (``step``, ``kind``, ``T``, ``nb``) and
+``engine.dispatch`` (``step``, ``kind``, ``T``, ``nb``, and on a paged
+engine ``walk``: the share of the linear page table the step's attention
+walk visits, also the ``engine.step.walk`` histogram) and
 ``engine.sample_commit`` (``step`` of the dispatch it commits). Inside
 ``sample_commit``, ``engine.device_wait`` covers only the ``np.asarray``
 transfers, so ``sample_commit`` minus ``device_wait`` is the host's
@@ -491,6 +493,10 @@ class ServingEngine:
                     backend=self.attn_backend.name, kind=kind)
                     for ph in TM.PHASES}
                 for kind in TM.STEP_KINDS}
+            self._walk_h = {
+                kind: tel.registry.histogram(
+                    TM.STEP_WALK, backend=self.attn_backend.name, kind=kind)
+                for kind in TM.STEP_KINDS}
             tel.registry.gauge(TM.QUEUE_DEPTH, fn=lambda: len(self.queue))
             self._overlap_h = tel.registry.histogram(
                 TM.STEP_OVERLAP, backend=self.attn_backend.name) \
@@ -500,6 +506,7 @@ class ServingEngine:
             self._ttft_hist = TM.Histogram()
             self._cow_counter = None
             self._phase_h = None
+            self._walk_h = None
             self._overlap_h = None
         self._t_radix = 0.0     # radix-lookup seconds within current step
         self._phase_span = None  # open profiler span of the current phase
@@ -1743,6 +1750,21 @@ class ServingEngine:
                                                    **attrs)
             self._phase_span.__enter__()
 
+    def _walk_attr(self, kind: str, T: int, nb: int) -> Dict[str, float]:
+        """Paged engines: the share of the linear page table that this
+        step's ``paged_attention`` walk visits — each slot's blocks up to
+        the last one its T lanes from ``slot_pos`` can attend, over
+        ``nb * P`` — observed into the ``engine.step.walk`` histogram of
+        ``kind`` and returned as the dispatch span's ``walk`` attribute."""
+        if not self.paged:
+            return {}
+        P = self._pt.shape[1]
+        last = np.minimum((self.slot_pos[:nb] + T - 1) // self.page_size,
+                          P - 1)
+        walk = float((last + 1).sum()) / (nb * P)
+        self._walk_h[kind].observe(walk)
+        return {'walk': walk}
+
     def _flush_async(self) -> None:
         """Drain the async pipeline: commit the pending dispatch (if any).
         Re-entrancy-safe — the pending slot is cleared before committing."""
@@ -1893,9 +1915,11 @@ class ServingEngine:
                              jnp.asarray(self._rt[:nb]),
                              self._pending_array()]
                 if obs:
+                    T_ = int(ptoks.shape[1])
+                    walk = self._walk_attr(kind, T_, nb)
                     _t2 = tel.now()
                     self._phase_to('dispatch', step=step_idx, kind=kind,
-                                   T=int(ptoks.shape[1]), nb=nb)
+                                   T=T_, nb=nb, **walk)
                 if want_logits:
                     self.states, nxt, drops, finite, logits = \
                         self._packed_step_logits(*args, **kw)
@@ -1913,9 +1937,11 @@ class ServingEngine:
                              jnp.asarray(self._rt[:nb]),
                              self._pending_array()]
                 if obs:
+                    T_ = int(tokens.shape[1])
+                    walk = self._walk_attr(kind, T_, nb)
                     _t2 = tel.now()
                     self._phase_to('dispatch', step=step_idx, kind=kind,
-                                   T=int(tokens.shape[1]), nb=nb)
+                                   T=T_, nb=nb, **walk)
                 if want_logits:
                     self.states, nxt, drops, finite, logits = \
                         self._chunk_step_logits(*args, **kw)

@@ -85,12 +85,19 @@ STEP_KINDS = ('prefill', 'decode', 'mixed')
 # Profiler spans (Telemetry.span) over the same intervals as the phases.
 # Nesting: engine.step > engine.host_schedule > engine.radix_lookup (one
 # per lookup), engine.step > engine.pack_layout, engine.dispatch (attrs
-# step, kind, T, nb), engine.sample_commit (step it commits: under the
-# async loop the previous one) > engine.device_wait (the np.asarray
-# transfers only; sample_commit's self time is the host's commit work).
+# step, kind, T, nb; walk when paged), engine.sample_commit (step it
+# commits: under the async loop the previous one) > engine.device_wait
+# (the np.asarray transfers only; sample_commit's self time is the host's
+# commit work).
 SPAN_STEP = 'engine.step'
 SPAN_DEVICE_WAIT = 'engine.device_wait'
 SPAN_PHASE = {ph: f'engine.{ph}' for ph in PHASES}
+
+# engine.step.walk{backend=,kind=} — paged engines: the share of the
+# linear (window-0) page table that a dispatch's paged_attention walk
+# visits, sum over slots of (last reachable block + 1) / (nb * P); the
+# same value is the engine.dispatch span's ``walk`` attribute.
+STEP_WALK = 'engine.step.walk'
 
 # engine.step.overlap_s{backend=} — host-side scheduling work (the
 # host_schedule + radix_lookup + pack_layout phases of step N+1) performed

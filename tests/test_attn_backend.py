@@ -126,6 +126,98 @@ def test_kernel_mla_matches_gather_oracle(ps):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
+@pytest.mark.parametrize('pool', ['bf16', 'int8', 'mla'])
+@pytest.mark.parametrize('T', [1, 16])
+def test_kernel_walk_ends_at_last_reachable_page(pool, T):
+    """On a linear table (window 0) the kernel attends no page past
+    ``(pos0 + T - 1) // ps``: table entries there pointing at empty pages
+    full of NaN (NaN scales for the int8 pool) give the output of null-page
+    entries bit for bit. A full walk would poison it through ``0 * NaN`` in
+    the value mix. Slots: an idle lane (pos0 0, no pages), and chunks whose
+    last query sits at the first and at the last offset of a page."""
+    B, KV, G, d, ps, P = 3, 2, 2, 16, 8, 6
+    r, dr = 12, 6                                  # MLA latent widths
+    pos0 = np.array([0, 2 * ps - T + 1, 3 * ps - T], np.int32)
+    last = (pos0 + T - 1) // ps
+    n_live = [0, int(pos0[1]) + T, int(pos0[2]) + T]   # written positions
+    NP = 1 + B * P                     # null page, then one page per entry
+    table = np.zeros((B, P), np.int32)
+    poisoned = np.zeros((B, P), np.int32)
+    cpos = np.full((NP, ps), -1, np.int32)
+    live = np.zeros(NP, bool)
+    live[0] = True                     # the null page: clean, never written
+    nxt = 1
+    for b in range(B):
+        for j in range(P):
+            if j * ps < n_live[b]:
+                table[b, j] = nxt
+                hi = min(n_live[b], (j + 1) * ps)
+                cpos[nxt, :hi - j * ps] = np.arange(j * ps, hi)
+                live[nxt] = True
+            poisoned[b, j] = table[b, j] if j <= last[b] else nxt
+            nxt += 1
+    live = jnp.asarray(live)[:, None, None, None]
+    poison = lambda x: jnp.where(live, x, jnp.nan).astype(x.dtype)
+    q = jax.random.normal(jax.random.PRNGKey(5), (B, T, KV, G, d))
+    kw = {}
+    if pool == 'mla':
+        kk = jax.random.PRNGKey(6)
+        q = jax.random.normal(kk, (B, T, 1, 2, r + dr))
+        k = jax.random.normal(jax.random.fold_in(kk, 1), (NP, ps, 1, r))
+        k2 = jax.random.normal(jax.random.fold_in(kk, 2), (NP, ps, 1, dr))
+        kw = dict(scale=(r + dr) ** -0.5, mla_split=r)
+        clean = (k, None, dict(k2_pages=k2))
+        dirty = (poison(k), None, dict(k2_pages=poison(k2)))
+    else:
+        k, v, ks, vs = _pool(4, NP, ps, KV, d, quant=pool == 'int8')
+        kw = dict(scale=d ** -0.5)
+        if pool == 'int8':
+            sc = lambda x: poison(x[..., None])[..., 0]
+            clean = (k, v, dict(k_scale_pages=ks, v_scale_pages=vs))
+            dirty = (k, v, dict(k_scale_pages=sc(ks), v_scale_pages=sc(vs)))
+        else:
+            k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+            clean = (k, v, {})
+            dirty = (poison(k), poison(v), {})
+    pos0 = jnp.asarray(pos0)
+    cpos = jnp.asarray(cpos)
+
+    def run(pages, tab):
+        kp, vp, extra = pages
+        return np.asarray(paged_attention(q, kp, vp, cpos, jnp.asarray(tab),
+                                          pos0, **kw, **extra,
+                                          interpret=True))
+    want = run(clean, table)
+    got = run(dirty, poisoned)
+    assert np.isfinite(want).all()
+    np.testing.assert_array_equal(got, want)
+    oracle = ref.paged_attention_ref(q, clean[0], clean[1], cpos,
+                                     jnp.asarray(table), pos0, **kw,
+                                     **clean[2])
+    np.testing.assert_allclose(want, np.asarray(oracle), **TOL)
+
+
+@pytest.mark.parametrize('T', [1, 16])
+def test_kernel_window_walks_every_ring_page(T):
+    """Sliding-window layers keep the full walk: ring tables map blocks to
+    no fixed positions. A ring that has wrapped (positions past its
+    length) and one that has not, against the gather oracle."""
+    B, KV, G, d, ps, window = 2, 2, 2, 16, 8, 12
+    Sc = 24 if T == 1 else 40            # ring length >= window + T - 1
+    P = Sc // ps
+    table = np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P)[:, ::-1]
+    lengths = [Sc + 13, T + 3]
+    k, v, _, _ = _pool(8, 1 + B * P, ps, KV, d)
+    cpos = _fill_positions(1 + B * P, ps, table, lengths, Sc)
+    q = jax.random.normal(jax.random.PRNGKey(9), (B, T, KV, G, d))
+    pos0 = jnp.asarray([le - T for le in lengths], jnp.int32)
+    args = (q, k, v, cpos, jnp.asarray(table.copy()), pos0)
+    got = paged_attention(*args, scale=d ** -0.5, window=window,
+                          interpret=True)
+    want = ref.paged_attention_ref(*args, scale=d ** -0.5, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
 # ================================================== model-step parity (dense)
 def _cfg(kind):
     base = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,

@@ -21,7 +21,8 @@ disabled mode.
 - **Profiler spans**: a telemetry-on engine run under ``jax.profiler``
   leaves every ``engine.*`` phase span, nested and with its attributes,
   one ``engine.dispatch`` per step, and span durations that add up to
-  the ``engine.step.phase_s`` totals.
+  the ``engine.step.phase_s`` totals. Its ``walk`` attribute and the
+  ``engine.step.walk`` histogram match a hand count of reachable pages.
 """
 import glob
 import json
@@ -249,6 +250,7 @@ def test_phase_spans_in_profiler_trace(tmp_path, async_loop):
     for d in disp:
         assert d[3]['kind'] in TM.STEP_KINDS
         assert d[3]['T'] in (1, eng.chunk_size) and d[3]['nb'] >= 1
+        assert 0 < d[3]['walk'] <= 1
         assert parent(d, TM.SPAN_STEP)[3]['step'] == d[3]['step']
     for ph in ('host_schedule', 'pack_layout'):
         for sp in by[TM.SPAN_PHASE[ph]]:
@@ -283,6 +285,47 @@ def test_phase_spans_in_profiler_trace(tmp_path, async_loop):
     for ph in TM.PHASES:
         assert abs(got[ph] - want[ph]) <= 0.05 * want[ph] + 1e-3, \
             (ph, got[ph], want[ph])
+
+
+def test_dispatch_walk_counts_reachable_pages():
+    """``walk`` on ``engine.dispatch`` and the ``engine.step.walk``
+    histogram: per step, the pages up to each slot's last reachable block,
+    counted by hand from the positions the step program was given, over
+    ``nb * P``."""
+    eng = _engine(True)
+    P = MAX_SEQ // PS
+    assert eng._pt.shape[1] == P
+    hand = []                                   # (T, walk) per dispatch
+    step = eng._chunk_step
+
+    def counted(*args, **kw):
+        tokens, pos = args[2], np.asarray(args[3])
+        T = tokens.shape[1]
+        reach = [min((int(p) + T - 1) // PS, P - 1) + 1 for p in pos]
+        hand.append((T, sum(reach) / (len(pos) * P)))
+        return step(*args, **kw)
+    eng._chunk_step = counted
+    attrs = []
+    span = eng.telemetry.span
+
+    def recording(name, **kw):
+        if name == TM.SPAN_PHASE['dispatch']:
+            attrs.append(kw)
+        return span(name, **kw)
+    eng.telemetry.span = recording
+    for r in _reqs():
+        eng.submit(r)
+    eng.run()
+    assert [a['T'] for a in attrs] == [T for T, _ in hand]
+    assert {T for T, _ in hand} == {1, eng.chunk_size}   # decode and chunk
+    assert [a['walk'] for a in attrs] == pytest.approx([w for _, w in hand])
+    assert max(w for _, w in hand) > 1 / P     # some slot reaches page 2+
+    for kind in TM.STEP_KINDS:
+        h = eng.telemetry.registry.histogram(
+            TM.STEP_WALK, backend=eng.attn_backend.name, kind=kind)
+        mine = [w for a, (_, w) in zip(attrs, hand) if a['kind'] == kind]
+        assert h.count == len(mine)
+        assert h.total == pytest.approx(sum(mine))
 
 
 # ------------------------------------------------------------- chaos traces

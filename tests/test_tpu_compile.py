@@ -62,18 +62,24 @@ def compile_for_chip(one_chip, no_persistent_cache):
     return run
 
 
-# (T, KV, G, d, page_size, pages per slot, dtype, window, int8 pool)
+# (T, KV, G, d, page_size, pages per slot, dtype, window, int8 pool,
+#  slots, pool pages)
 ATTN = {
-    'gemma3-1b-decode': (1, 1, 4, 256, 16, 64, BF16, 512, False),
-    'gemma3-1b-chunk64': (64, 1, 4, 256, 16, 64, BF16, 512, False),
-    'mistral-7b-chunk16': (16, 8, 4, 128, 16, 64, BF16, 0, False),
-    'mistral-7b-int8': (16, 8, 4, 128, 32, 32, BF16, 0, True),
+    'gemma3-1b-decode': (1, 1, 4, 256, 16, 64, BF16, 512, False, B, NP),
+    'gemma3-1b-chunk64': (64, 1, 4, 256, 16, 64, BF16, 512, False, B, NP),
+    'mistral-7b-chunk16': (16, 8, 4, 128, 16, 64, BF16, 0, False, B, NP),
+    'mistral-7b-int8': (16, 8, 4, 128, 32, 32, BF16, 0, True, B, NP),
+    # the benchmark cell's grid: 64 slots x 256 pages, the bounded walk
+    'mistral-7b-cell-decode': (1, 8, 4, 128, 16, 256, BF16, 0, False, 64,
+                               10000),
+    'mistral-7b-cell-chunk64': (64, 8, 4, 128, 16, 256, BF16, 0, False, 64,
+                                10000),
 }
 
 
 @pytest.mark.parametrize('case', sorted(ATTN))
 def test_paged_attention_compiles(case, compile_for_chip):
-    T, KV, G, d, ps, P, dt, window, quant = ATTN[case]
+    T, KV, G, d, ps, P, dt, window, quant, B, NP = ATTN[case]
 
     def f(q, k, v, cpos, table, pos0, *scales):
         ks, vs = scales if quant else (None, None)
