@@ -35,8 +35,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import model as BM
-    from bench import weights as W
+    from bench import manifest as M
     from repro.kernels import ops
     from repro.models import attention as A
     from repro.models.attn_backend import get_backend
@@ -45,10 +44,10 @@ def main(argv=None) -> int:
 
     jax.config.update('jax_enable_compilation_cache', False)
     ops._interpret = lambda: False            # compile the kernels for Mosaic
-    spec = W.load_spec(args.config)
+    spec = M.data('configs', f'{args.config}.json')
     geo = dict(spec['engine'])
     geo['num_pages'] = args.num_pages or geo['num_pages']
-    cfg = BM.model_config(args.config, spec)
+    cfg = M.arch(spec).model_config(args.config, spec)
     model = Model(cfg)
     topo = topologies.get_topology_desc(platform='tpu',
                                         topology_name='v5e:2x2')

@@ -4,8 +4,8 @@
     python3 bench/control.py --workload <cell> --seconds <s> --seeds 1 2 3
 
 Runs the cell once per seed in this one process, as ``bench/run.py``
-would, and also runs the int8 control (``bench/reference.py``) over the
-same sample. Prints one JSON line per seed: the program's widest and mean
+would, and also runs the int8 control (the architecture's ``score``) over
+the same sample. Prints one JSON line per seed: the program's widest and mean
 logit gap (lower readings, over many seeds) and the control's (upper ones).
 The benchmark's own runs never run the control.
 """

@@ -7,7 +7,10 @@ The window then drives ``ServingEngine.submit`` and ``step_once`` for
 ``seconds``, with the engine's queue never holding fewer than ``max_slots``
 requests, and stamps every token on the client's side as it arrives.
 Afterwards the engine is freed and the plain reference checks a seeded
-sample of the finished requests.
+sample of the finished requests. The configuration's architecture
+(``bench/arch/<model_type>.py``) brings the parameter tree, the reference
+and the work counts; the deployment path is held the same for all
+(``bench/model.py``).
 
 With ``trace`` the window runs with the engine's telemetry on and under the
 JAX profiler, and the cell's per-layer metrics are read from both; without
@@ -30,9 +33,7 @@ import numpy as np
 
 from bench import generator as G
 from bench import manifest as M
-from bench import reference
 from bench import tracing
-from bench import weights as W
 
 WARM_UID = 10 ** 12             # warm-up requests' uids start here
 SAMPLE_MIN, SAMPLE_MAX, SAMPLE_TOKENS = 3, 8, 512
@@ -90,7 +91,8 @@ class Tracked:
 class Record:
     """What one run measured; each metric's reader takes what it needs."""
     cell: str
-    dims: W.Dims
+    dims: object                      # the architecture's sizes
+    arch: object                      # its module (bench/arch/)
     peaks: dict
     setup_s: float
     window_s: float
@@ -321,7 +323,7 @@ def compare(spec: dict, seed: int, sample, control: bool = False) -> dict:
     and the mean (``mean_gap``). With ``control``, the same of the token
     the int8 control puts first (``control_gap``, ``control_mean_gap``)."""
     seqs = [np.concatenate([p, g]) for p, g in sample]
-    rows = reference.score(spec, seed, seqs, control=control)
+    rows = M.arch(spec).score(spec, seed, seqs, control=control)
     gaps, ctl = [], []
     for (p, g), row in zip(sample, rows):
         sl = slice(len(p) - 1, len(p) - 1 + len(g))
@@ -364,6 +366,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     bench = M.load()
     cell = M.workload(bench, cell_name)
     spec = M.config(bench, cell['config'])
+    arch = M.arch(spec)
     mix = M.mix(cell['traffic'])
     devices, peaks = find_device(cell['chips'])
     cache = use_cache()
@@ -371,9 +374,9 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     log(f'device {devices[0].device_kind} x{len(devices)}; cache {cache}')
 
     from bench import model as BM
-    eng, _ = BM.build(cell['config'], spec, seed, telemetry=trace)
+    eng, _ = BM.build(arch, cell['config'], spec, seed, telemetry=trace)
     log(f'engine built at {time.monotonic() - t_start:.1f}s')
-    dims = W.dims_of(spec)
+    dims = arch.dims_of(spec)
     warm_compile(eng, dims.vocab, seed)
     log(f'shapes warmed at {time.monotonic() - t_start:.1f}s; compile '
         '(programs, s, cache hits) %s' % (clock.snapshot(),))
@@ -385,7 +388,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         mem = max(int((d.memory_stats() or {}).get('peak_bytes_in_use', 0))
                   for d in devices)
         rec = Record(
-            cell=cell_name, dims=dims, peaks=peaks,
+            cell=cell_name, dims=dims, arch=arch, peaks=peaks,
             setup_s=timing['t0'] - t_start, window_s=timing['window_s'],
             tokens=cl.tokens, itl_s=cl.itl, steps=cl.steps,
             telemetry=cl.telemetry_record(*timing['telemetry']))

@@ -3,14 +3,17 @@
 A configuration is ``bench/configs/<config>.json``, a traffic mix
 ``bench/traffic/<traffic>.json``, a metric's reader
 ``bench/metrics/<metric>.py`` and a cell's correctness limit
-``bench/limits/<cell>.json``. Adding one is adding its file and its entry;
-nothing here changes.
+``bench/limits/<cell>.json``, and a configuration's architecture
+``bench/arch/<model_type>.py``, by the ``model_type`` its file states.
+Adding one is adding its file and its entry; nothing here changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import re
+import sys
 from typing import Callable, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -76,13 +79,39 @@ def metrics_for(bench: dict, cell: dict, group: str) -> List[dict]:
             if (lists(m) if 'workloads' in m else m['moves'] in names)]
 
 
+def _module(path: str, name: str, what: str):
+    """The module in file ``path``, loaded once under ``name``."""
+    mod = sys.modules.get(name)
+    if mod is not None and getattr(mod, '__file__', None) == path:
+        return mod
+    if not os.path.exists(path):
+        raise ManifestError(f'no {what} {os.path.relpath(path, ROOT)}')
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod         # dataclasses look their module up here
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return mod
+
+
 def reader(name: str) -> Callable:
     """``read(rec) -> float | None`` from ``bench/metrics/<name>.py``."""
-    path = os.path.join(HERE, 'metrics', f'{name}.py')
-    if not os.path.exists(path):
-        raise ManifestError(f'no reader bench/metrics/{name}.py')
-    spec = importlib.util.spec_from_file_location(
-        'bench_metric_' + name.replace('.', '_').replace('-', '_'), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(os.path.join(HERE, 'metrics', f'{name}.py'),
+                   'bench_metric_' + re.sub(r'\W', '_', name),
+                   'reader').read
+
+
+def arch(spec: dict):
+    """The architecture module of a configuration (``bench/arch/``'s
+    interface), ``bench/arch/<model_type>.py``, found by the
+    ``model_type`` that the configuration file states."""
+    kind = spec.get('model_type')
+    if not isinstance(kind, str) or \
+            not re.fullmatch(r'[A-Za-z0-9_][A-Za-z0-9_-]*', kind):
+        raise ManifestError(f'model_type {kind!r} cannot name a file '
+                            'bench/arch/<model_type>.py')
+    return _module(os.path.join(HERE, 'arch', f'{kind}.py'),
+                   'bench_arch_' + re.sub(r'\W', '_', kind), 'architecture')

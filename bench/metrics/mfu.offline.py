@@ -1,6 +1,6 @@
-"""Model FLOPs of the window's work (bench/work.py: matmuls and attention
-over the real context) per second of window, as % of the chip's bf16
-peak."""
+"""Model FLOPs of the window's work (the architecture's ``model_flops``:
+matmuls and attention over the real context) per second of window, as % of
+the chip's bf16 peak."""
 from bench.readers import mfu
 
 

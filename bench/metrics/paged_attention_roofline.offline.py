@@ -1,9 +1,9 @@
 """Share of its roofline the paged-attention kernel reaches: the least
-time its useful work needs (valid context only, bench/work.py) over its
-device time in the trace, in %."""
-from bench import work
+time its useful work needs (valid context only, the architecture's
+``paged_attention_work``) over its device time in the trace, in %."""
 from bench.readers import PAGED_ATTENTION, kernel_roofline
 
 
 def read(rec):
-    return kernel_roofline(rec, PAGED_ATTENTION, work.paged_attention_work)
+    return kernel_roofline(rec, PAGED_ATTENTION,
+                           rec.arch.paged_attention_work)

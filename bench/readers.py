@@ -5,7 +5,6 @@ import math
 from typing import Optional
 
 from bench import tracing
-from bench import work
 
 PAGED_ATTENTION = r'paged_kernel|paged_attention'   # kernel name in a trace
 STEP_PROGRAM = r'chunk_step|(^|_)step\b'            # engine step programs
@@ -31,7 +30,7 @@ def model_flops(rec) -> Optional[int]:
     steps = window_lanes(rec)
     if steps is None:
         return None
-    return sum(work.model_flops(rec.dims, lanes) for lanes in steps)
+    return sum(rec.arch.model_flops(rec.dims, lanes) for lanes in steps)
 
 
 def mfu(rec, seconds: float) -> Optional[float]:
@@ -44,8 +43,9 @@ def mfu(rec, seconds: float) -> Optional[float]:
 
 
 def kernel_roofline(rec, pattern: str, work_fn) -> Optional[float]:
-    """Least time the chip needs for the kernel's useful work over its
-    device time in the trace, in %. None where the trace has no such
+    """Least time the chip needs for the kernel's useful work
+    (``work_fn(dims, lanes) -> (flops, bytes)`` per step) over its device
+    time in the trace, in %. None where the trace has no such
     kernel or the window did no such work."""
     steps = window_lanes(rec)
     if rec.trace is None or not rec.trace['planes'] or steps is None:

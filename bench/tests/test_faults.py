@@ -60,7 +60,7 @@ def test_control_reads_above_the_limit(tiny_tree):
     bench = M.load()
     cell = M.workload(bench, CELL)
     spec = M.config(bench, cell['config'])
-    eng, cfg = BM.build(cell['config'], spec, SEED)
+    eng, cfg = BM.build(M.arch(spec), cell['config'], spec, SEED)
     reqs = [Request(uid=s.idx, prompt=s.prompt,
                     max_new_tokens=s.max_new_tokens)
             for s in itertools.islice(G.Stream(M.mix(cell['traffic']),

@@ -109,9 +109,9 @@ def test_listed_metrics_read_or_fail_the_run(tmp_path, monkeypatch, kernel):
 
 
 def test_infinite_tail_fails_the_run():
-    rec = harness.Record(cell='c', dims=None, peaks={}, setup_s=1.0,
-                         window_s=1.0, tokens=1, itl_s=[0.1, math.inf],
-                         steps=1)
+    rec = harness.Record(cell='c', dims=None, arch=None, peaks={},
+                         setup_s=1.0, window_s=1.0, tokens=1,
+                         itl_s=[0.1, math.inf], steps=1)
     cell = {'name': 'c'}
     bench = {'end_to_end': [{'name': 'itl_p95_ms', 'unit': 'ms'}],
              'per_layer': []}

@@ -1,8 +1,9 @@
 """A benchmark tree at a tiny size, for running the harness on the CPU.
 
-``tree(tmp)`` writes BENCHMARK.json and the data files of a tiny cell under
-``tmp``, derived from the real configuration and mix files with small widths
-and lengths, and points ``bench.manifest`` at them.
+``tree(tmp, monkeypatch, cells)`` writes BENCHMARK.json and the data files
+of tiny cells under ``tmp``, derived from the real configuration and mix
+files with small widths (each architecture's ``small``) and lengths, and
+points ``bench.manifest`` at them.
 """
 import json
 import os
@@ -11,14 +12,14 @@ import shutil
 from bench import manifest as M
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SMALL = {'hidden_size': 64, 'intermediate_size': 128,
-         'num_attention_heads': 4, 'num_hidden_layers': 2,
-         'vocab_size': 256, 'max_position_embeddings': 256}
 ENGINE = {'max_slots': 8, 'max_seq': 128, 'chunk_size': 16,
           'page_size': 16, 'num_pages': 96}
 LENGTHS = {'prompt': (24, 8, 40), 'output': (6, 3, 12)}
 PEAKS = {'bf16_flops': 1e12, 'hbm_bytes_per_s': 1e11}   # made up
 PLANE = '/device:TPU:0'
+# tiny cell -> (its configuration, the real configuration, the real mix)
+CELLS = {'tiny-mistral.offline': ('tiny-mistral', 'mistral-7b-v0.3',
+                                  'offline-decode')}
 
 
 def on_cpu(chips: int):
@@ -41,9 +42,7 @@ def device_trace(kernel: str = 'paged_kernel'):
 def small_config(name: str) -> dict:
     with open(os.path.join(BENCH, 'configs', f'{name}.json')) as f:
         spec = json.load(f)
-    spec.update(SMALL, engine=dict(ENGINE), num_key_value_heads=2,
-                head_dim=16)
-    return spec
+    return dict(M.arch(spec).small(spec), engine=dict(ENGINE))
 
 
 def small_mix(name: str, **over) -> dict:
@@ -56,20 +55,25 @@ def small_mix(name: str, **over) -> dict:
     return mix
 
 
-def tree(tmp, monkeypatch) -> dict:
-    """Write the tiny tree and point the manifest at it; -> the manifest."""
+def tree(tmp, monkeypatch, cells=CELLS) -> dict:
+    """Write the tiny tree and point the manifest at it; -> the manifest.
+    A tiny cell reports the metrics of the real cell of its real
+    configuration and mix."""
     here = os.path.join(tmp, 'bench')
-    shutil.copytree(os.path.join(BENCH, 'metrics'),
-                    os.path.join(here, 'metrics'))
+    for sub in ('metrics', 'arch'):
+        shutil.copytree(os.path.join(BENCH, sub), os.path.join(here, sub),
+                        ignore=shutil.ignore_patterns('__pycache__'))
     shutil.copy(os.path.join(BENCH, 'peaks.json'), here)
     os.makedirs(os.path.join(here, 'configs'))
     os.makedirs(os.path.join(here, 'traffic'))
-    cells = {'tiny-mistral.offline': ('tiny-mistral', 'mistral-7b-v0.3',
-                                      'offline-decode')}
     with open(os.path.join(M.ROOT, 'BENCHMARK.json')) as f:
         bench = json.load(f)
+    real = {(w['config'], w['traffic']): w['name']
+            for w in bench['workloads']}
+    rename = {}
     bench['configs'], bench['workloads'] = [], []
     for cell, (cfg, real_cfg, real_mix) in cells.items():
+        rename.setdefault(real.get((real_cfg, real_mix)), []).append(cell)
         with open(os.path.join(here, 'configs', f'{cfg}.json'), 'w') as f:
             json.dump(small_config(real_cfg), f)
         mix = small_mix(real_mix)
@@ -82,11 +86,10 @@ def tree(tmp, monkeypatch) -> dict:
         bench['workloads'].append({'name': cell, 'config': cfg,
                                    'traffic': f'tiny-{real_mix}', 'chips': 1,
                                    'why': 'tiny'})
-    rename = {'mistral-7b-v0.3.offline-decode': 'tiny-mistral.offline'}
     for m in bench['end_to_end'] + bench['per_layer']:
         if 'workloads' in m:
-            m['workloads'] = [rename[w] for w in m['workloads']
-                              if w in rename]
+            m['workloads'] = [c for w in m['workloads']
+                              for c in rename.get(w, [])]
     with open(os.path.join(tmp, 'BENCHMARK.json'), 'w') as f:
         json.dump(bench, f)
     monkeypatch.setattr(M, 'ROOT', str(tmp))
